@@ -52,7 +52,7 @@ def main():
 
     # the engine is a WI workload: utilization + queue depth become hints
     for tick in range(200):
-        eng.step()
+        eng.step_once()
         if tick % 10 == 0:
             ep.set_runtime_hints({
                 "x-utilization": eng.utilization(),

@@ -161,7 +161,7 @@ class ParallelConfig:
     # hillclimb levers (see EXPERIMENTS.md §Perf)
     gather_barrier: bool = False   # pin FSDP weight gathers at loop-body top
     moe_cap_shard: bool = False    # shard MoE dispatch buffers over data
-    # attention impl: dense | flash | pallas
+    # attention impl: dense | flash (pure-JAX chunked)
     attn_impl: str = "flash"
     flash_q_chunk: int = 512
     flash_kv_chunk: int = 512
@@ -172,6 +172,12 @@ class ParallelConfig:
     grad_compression: str = "none"
     # collective schedule for the DP gradient reduction under shard_map paths
     dp_collective: str = "all_reduce"  # all_reduce | reduce_scatter
+
+    def __post_init__(self):
+        if self.attn_impl not in ("dense", "flash"):
+            raise NotImplementedError(
+                f"attn_impl={self.attn_impl!r}: only 'dense' and 'flash' "
+                "are implemented")
 
     def axis_names(self) -> Tuple[str, ...]:
         return ("pod", "data", "model") if self.pod > 1 else ("data", "model")

@@ -3,8 +3,8 @@
 Public entry matches models/layers/flash.flash_attention: q [B,S,H,hd],
 k/v [B,S,K,hd].  Forward = Pallas kernel; backward = the pure-JAX chunked
 VJP from models/layers/flash (identical math, recomputation-based).
-``interpret=True`` executes the kernel body in Python on CPU (how this repo
-validates TPU kernels offline); on a real TPU backend pass interpret=False.
+The kernel compiles for the TPU by default; ``interpret=True`` executes its
+body in Python on the CPU (how the tests validate it without a chip).
 """
 from __future__ import annotations
 
@@ -38,7 +38,7 @@ def _unfold(out, dims):
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
 def flash_attention_kernel(q, k, v, cfg: AttnConfig, q_chunk=512,
-                           kv_chunk=512, interpret=True):
+                           kv_chunk=512, interpret=False):
     qf, kf, vf, dims = _fold(q, k, v)
     scale = (cfg.query_scale if cfg.query_scale is not None
              else 1.0 / np.sqrt(q.shape[-1]))
@@ -69,6 +69,6 @@ flash_attention_kernel.defvjp(_fwd, _bwd)
 
 
 def attention(q, k, v, cfg: AttnConfig, q_chunk=512, kv_chunk=512,
-              interpret=True):
+              interpret=False):
     """Drop-in attention entry point selecting the Pallas kernel."""
     return flash_attention_kernel(q, k, v, cfg, q_chunk, kv_chunk, interpret)

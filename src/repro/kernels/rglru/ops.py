@@ -6,7 +6,7 @@ import jax.numpy as jnp
 from repro.kernels.rglru.rglru import rglru_scan as _kernel_scan
 
 
-def rglru_mixer(x_gated, log_a, *, chunk=256, interpret=True):
+def rglru_mixer(x_gated, log_a, *, chunk=256, interpret=False):
     """x_gated [B,S,W] (input-gated), log_a [B,S,W] -> h [B,S,W] f32.
 
     Matches layers.rglru.rglru_scan (zero initial state).

@@ -7,7 +7,7 @@ import jax.numpy as jnp
 from repro.kernels.ssd.ssd import ssd_scan
 
 
-def ssd_mixer(x, dt, a_log, Bm, Cm, *, chunk=128, interpret=True):
+def ssd_mixer(x, dt, a_log, Bm, Cm, *, chunk=128, interpret=False):
     """x [B,S,H,P]; dt [B,S,H] (post-softplus); a_log [H];
     Bm/Cm [B,S,G,N] -> y [B,S,H,P].  Matches layers.ssd.ssd_chunked."""
     B, S, H, P = x.shape

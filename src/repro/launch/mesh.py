@@ -1,9 +1,7 @@
 """Mesh construction.  Functions, not module-level constants — importing this
-module never touches jax device state.
-
-``axis_types`` is deliberately not passed: newer jax defaults every axis to
-``AxisType.Auto`` already, and older jax (<0.5) has neither the enum nor the
-kwarg — omitting it is the one spelling that works everywhere.
+module never touches jax device state.  Every axis is ``AxisType.Auto``
+(``jax.make_mesh``'s default): shardings flow from the arguments and the
+``constrain`` calls in model code.
 """
 from __future__ import annotations
 

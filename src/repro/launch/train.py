@@ -8,10 +8,33 @@
 is used (requires a real TPU slice — the production mesh shardings come
 from launch/steps.py).  ``--devices N`` forces N virtual host devices
 (set before jax import, so it must be the launcher, not the library).
+``elastic_trainer`` builds the standalone trainer and its fault injector
+for ``main`` and ``chip_smoke.py --chips 4``.
 """
 import argparse
 import os
 import sys
+
+
+def elastic_trainer(cfg, *, ckpt_dir, steps, model_axis=1, batch=8, seq=64,
+                    lr=1e-3, ckpt_every=20, seed=0, data_cfg=None):
+    """A standalone ``WITrainer`` over every visible device, and the
+    ``FaultInjector`` that sends it platform events.  ``seed`` draws the
+    initial weights and, unless ``data_cfg`` is given, the token stream."""
+    from repro.configs.base import RunConfig
+    from repro.core.global_manager import GlobalManager
+    from repro.data.pipeline import DataConfig
+    from repro.runtime.faults import FaultInjector
+    from repro.runtime.trainer import WITrainer
+
+    rcfg = RunConfig(model=cfg, seed=seed, learning_rate=lr,
+                     warmup_steps=max(steps // 10, 1), total_steps=steps)
+    gm = GlobalManager(hint_rate_per_s=1e6, hint_burst=1e6)
+    tr = WITrainer(rcfg, gm, ckpt_dir=ckpt_dir, model_axis=model_axis,
+                   ckpt_every=ckpt_every, batch_override=batch,
+                   seq_override=seq,
+                   data_cfg=data_cfg or DataConfig(seed=seed))
+    return tr, FaultInjector(gm, tr.workload)
 
 
 def main():
@@ -37,24 +60,17 @@ def main():
 
     import tempfile
     from repro.configs.archs import ARCHS, smoke_config
-    from repro.configs.base import RunConfig
-    from repro.core.global_manager import GlobalManager
     from repro.data.pipeline import DataConfig
-    from repro.runtime.faults import FaultInjector
-    from repro.runtime.trainer import WITrainer
+    from repro.launch import compile_cache
 
+    compile_cache.enable()
     cfg = smoke_config(args.arch) if args.smoke else ARCHS[args.arch]
-    rcfg = RunConfig(model=cfg, learning_rate=args.lr,
-                     warmup_steps=max(args.steps // 10, 1),
-                     total_steps=args.steps)
-    gm = GlobalManager(hint_rate_per_s=1e6, hint_burst=1e6)
     dcfg = (DataConfig(kind="file", path=args.data) if args.data
             else DataConfig())
-    tr = WITrainer(rcfg, gm, ckpt_dir=args.ckpt_dir or tempfile.mkdtemp(),
-                   model_axis=args.model_axis, ckpt_every=args.ckpt_every,
-                   batch_override=args.batch, seq_override=args.seq,
-                   data_cfg=dcfg)
-    inj = FaultInjector(gm, "train-job")
+    tr, inj = elastic_trainer(
+        cfg, ckpt_dir=args.ckpt_dir or tempfile.mkdtemp(), steps=args.steps,
+        model_axis=args.model_axis, batch=args.batch, seq=args.seq,
+        lr=args.lr, ckpt_every=args.ckpt_every, data_cfg=dcfg)
 
     def hooks(t):
         if args.inject_eviction_at and t.step == args.inject_eviction_at:

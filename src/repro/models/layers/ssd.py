@@ -109,7 +109,9 @@ def ssd_chunked(x, dt, a_log, Bm, Cm, chunk, init_state=None):
     segl = segh.transpose(0, 1, 3, 4, 2)                    # [B,nc,G,rep,L]
     dmat = segl[..., :, None] - segl[..., None, :]          # [B,nc,G,rep,l,s]
     causal = jnp.tril(jnp.ones((chunk, chunk), bool))
-    lmat = jnp.where(causal, jnp.exp(dmat), 0.0)
+    # mask before the exp: above the diagonal dmat is positive and can
+    # overflow, and exp's gradient there would be 0 * inf = NaN
+    lmat = jnp.exp(jnp.where(causal, dmat, -jnp.inf))
     dtl = dtc.reshape(Bsz, nc, chunk, G, rep).transpose(0, 1, 3, 4, 2)
     sc = scores.transpose(0, 1, 2, 4, 3, 5)                 # [B,nc,G,rep,l,s]
     w = sc * lmat * dtl[..., None, :]

@@ -72,6 +72,31 @@ def deployment_hints_from(rcfg: RunConfig, ckpt_every: int,
     }
 
 
+def parallel_config(dp: int, model_axis: int,
+                    throttled: bool = False) -> ParallelConfig:
+    """The trainer's layout on a dp x model_axis mesh.  Rematerialisation
+    stays at ``ParallelConfig``'s default: without it the activations of a
+    published-width step do not fit one chip."""
+    return ParallelConfig(pod=1, data=dp, model=model_axis, fsdp=False,
+                          seq_shard_acts=False, attn_impl="dense",
+                          microbatch=2 if throttled else 0)
+
+
+def build_step(cfg: ModelConfig, rcfg: RunConfig, pcfg: ParallelConfig,
+               mesh: Mesh, batch_like: Dict):
+    """The jitted train step on ``mesh`` (params and optimizer state
+    donated) with its shardings: (step, pshard, oshard, bshard, rules).
+    Tracing needs ``SH.set_mesh(mesh, rules)`` in effect."""
+    pshard, oshard, rules = ST.train_shardings(cfg, pcfg, mesh)
+    bshard = {k: NamedSharding(mesh, P("data", *([None] * (v.ndim - 1))))
+              for k, v in batch_like.items()}
+    step = jax.jit(ST.build_train_fn(cfg, pcfg, rcfg, mesh),
+                   in_shardings=(pshard, oshard, bshard),
+                   out_shardings=(pshard, oshard, None),
+                   donate_argnums=(0, 1))
+    return step, pshard, oshard, bshard, rules
+
+
 class WITrainer:
     def __init__(self, rcfg: RunConfig, gm: GlobalManager,
                  ckpt_dir: str, devices: Optional[Sequence] = None,
@@ -125,21 +150,11 @@ class WITrainer:
         self.active_devices = devices
         dev_array = np.asarray(devices).reshape(dp, self.model_axis)
         self.mesh = Mesh(dev_array, ("data", "model"))
-        self.pcfg = ParallelConfig(
-            pod=1, data=dp, model=self.model_axis, fsdp=False,
-            seq_shard_acts=False, attn_impl="dense", remat="none",
-            microbatch=2 if self._throttled else 0)
-        self.pshard, self.oshard, rules = ST.train_shardings(
-            self.cfg, self.pcfg, self.mesh)
+        self.pcfg = parallel_config(dp, self.model_axis, self._throttled)
+        (self._train_step, self.pshard, self.oshard, self.bshard,
+         rules) = build_step(self.cfg, self.rcfg, self.pcfg, self.mesh,
+                             self.data.batch_at(0))
         SH.set_mesh(self.mesh, rules)
-        fn = ST.build_train_fn(self.cfg, self.pcfg, self.rcfg, self.mesh)
-        self.bshard = {
-            k: NamedSharding(self.mesh, P("data", *([None] * (v.ndim - 1))))
-            for k, v in self.data.batch_at(0).items()}
-        self._train_step = jax.jit(
-            fn, in_shardings=(self.pshard, self.oshard, self.bshard),
-            out_shardings=(self.pshard, self.oshard, None),
-            donate_argnums=(0, 1))
         self.dp = dp
 
     def _init_state(self):
@@ -185,7 +200,10 @@ class WITrainer:
     def _on_platform_event(self, event: Dict):
         self._pending_events.append(event)
 
-    def _drain_events(self):
+    def poll_events(self):
+        """Standalone mode: apply the platform events received so far
+        (resize, throttle) without taking a step; ``run`` calls it before
+        every step."""
         evs, self._pending_events = self._pending_events, []
         for e in evs:
             kind = e.get("event")
@@ -321,7 +339,7 @@ class WITrainer:
 
     def run(self, n_steps: int, step_callback: Optional[Callable] = None):
         while self.step < n_steps:
-            self._drain_events()
+            self.poll_events()
             self.step_once()
             if step_callback:
                 step_callback(self)

@@ -64,10 +64,19 @@ def sample(logits, temperature: float, key):
     return jax.random.categorical(key, logits / temperature, axis=-1)
 
 
+def decode_fn(cfg, pcfg):
+    """The engine's jitted batched decode step.  The cache argument is
+    donated: the step writes the new cache into the old one's buffers, so
+    the device holds one KV cache, not two."""
+    import jax
+    from repro.models import model as M
+    return jax.jit(lambda p, c, t: M.decode_step(cfg, pcfg, p, c, t),
+                   donate_argnums=1)
+
+
 class ServingEngine:
-    """Single-host engine (tests + examples + the serving tenant); the
-    distributed variant runs the same logic with pjit'd prefill/decode
-    (launch/serve.py)."""
+    """Single-device engine, used by the launcher (``launch/serve.py``),
+    the tests, the examples and the serving tenant."""
 
     def __init__(self, cfg, pcfg, params, batch_slots: int = 4,
                  max_len: int = 256, seed: int = 0,
@@ -94,8 +103,7 @@ class ServingEngine:
             from repro.models import model as M
             self._key = jax.random.PRNGKey(seed)
             self._cache = M.init_cache(cfg, batch_slots, max_len)
-            self._decode = jax.jit(
-                lambda p, c, t: M.decode_step(cfg, pcfg, p, c, t))
+            self._decode = decode_fn(cfg, pcfg)
         reg = registry if registry is not None \
             else obs.MetricsRegistry(enabled=False)
         self._registry = reg
@@ -345,10 +353,6 @@ class ServingEngine:
                     self._on_complete(r)
         self.stats["batches"] += 1
         return len(live)
-
-    # legacy name: step_once is the tenant-facing spelling
-    def step(self) -> int:
-        return self.step_once()
 
     def run_until_drained(self, max_steps: int = 10_000):
         steps = 0

@@ -40,14 +40,8 @@ def test_ring_allreduce_matches_psum():
             s, err = ring_allreduce_q(xs[0], "pod", 4, block=64)
             return s[None], err[None]
 
-        if hasattr(jax, "shard_map"):       # jax >= 0.5
-            smapped = jax.shard_map(body, mesh=mesh, in_specs=P("pod"),
-                                    out_specs=P("pod"), check_vma=False)
-        else:                               # jax 0.4.x
-            from jax.experimental.shard_map import shard_map
-            smapped = shard_map(body, mesh=mesh, in_specs=P("pod"),
-                                out_specs=P("pod"), check_rep=False)
-        f = jax.jit(smapped)
+        f = jax.jit(jax.shard_map(body, mesh=mesh, in_specs=P("pod"),
+                                  out_specs=P("pod"), check_vma=False))
         s, err = f(x)
         exact = np.asarray(x).sum(0)
         got = np.asarray(s)

@@ -158,3 +158,16 @@ def test_rglru_kernel_steep_decay_no_overflow():
     assert np.isfinite(np.asarray(out)).all()
     ref = lru_ref.reference(x, log_a)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=1e-5)
+
+
+def test_rglru_kernel_channel_blocks():
+    """Splitting W over the grid's channel-block axis changes nothing: each
+    block carries its own slice of the state."""
+    from repro.kernels.rglru.rglru import BLOCK_W, rglru_scan
+    ks = jax.random.split(jax.random.PRNGKey(3), 2)
+    x = rand(ks[0], (2, 64, 2 * BLOCK_W), jnp.float32)
+    log_a = -jax.nn.softplus(rand(ks[1], (2, 64, 2 * BLOCK_W), jnp.float32))
+    out = rglru_scan(x, log_a, chunk=32, interpret=True)
+    np.testing.assert_allclose(np.asarray(out),
+                               np.asarray(lru_ref.reference(x, log_a)),
+                               atol=1e-4, rtol=1e-3)

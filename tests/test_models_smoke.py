@@ -116,3 +116,32 @@ def test_sub_quadratic_flags():
     for n in ("gemma2-27b", "gemma2-9b", "llama3-405b", "minitron-8b",
               "granite-moe-1b-a400m", "whisper-tiny", "internvl2-26b"):
         assert not ARCHS[n].sub_quadratic, n
+
+
+@pytest.mark.parametrize("impl", ["pallas", "splash", ""])
+def test_unimplemented_attn_impl_raises(impl):
+    """Only the dense and pure-JAX flash paths exist; any other name must
+    fail at configuration time instead of silently running one of them."""
+    with pytest.raises(NotImplementedError, match="attn_impl"):
+        ParallelConfig(data=1, model=1, attn_impl=impl)
+
+
+def test_ssd_chunked_grads_finite_under_steep_decay():
+    """Across a 256-step chunk the summed log-decay reaches -700: the
+    masked (upper) half of the decay matrix then holds exp(+700).  It must
+    be masked before the exp, or its zero cotangent times inf is a NaN
+    gradient (mamba2-370m's chunk and initial decay do this)."""
+    from repro.models.layers.ssd import ssd_chunked
+    ks = jax.random.split(KEY, 3)
+    x = jax.random.normal(ks[0], (1, 512, 2, 8))
+    Bm = jax.random.normal(ks[1], (1, 512, 1, 16))
+    Cm = jax.random.normal(ks[2], (1, 512, 1, 16))
+    dt = jnp.ones((1, 512, 2))
+    a_log = jnp.ones((2,))                  # a = -e per step
+
+    def loss(x, dt, a_log):
+        return ssd_chunked(x, dt, a_log, Bm, Cm, 256)[0].sum()
+
+    grads = jax.grad(loss, argnums=(0, 1, 2))(x, dt, a_log)
+    for g in grads:
+        assert np.isfinite(np.asarray(g)).all()
