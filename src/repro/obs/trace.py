@@ -16,20 +16,31 @@ Exports:
     (count/total/mean/max), the per-phase profile ``benchmarks/run.py
     --profile`` commits into BENCH_sched.json.
 
-A disabled tracer's ``span()`` returns one shared no-op context manager
-(no allocation), and ``begin``/``end`` return immediately — the scheduler
-instruments unconditionally against the process-wide default tracer, which
-starts disabled, so the hot path pays a handful of attribute checks per
-tick and nothing per VM.
+A tracer has up to two sinks.  The **ring** (``enabled=True``) is the
+flight recorder above.  The **profiler sink** (``profiler=True``) writes
+each span as a ``jax.profiler.TraceAnnotation`` (a ``step()`` as a
+``StepTraceAnnotation``), so the spans land in any running JAX profiler
+session on the same timeline as the device's operations; outside a
+session an annotation costs about a microsecond.  Only a tracer with the
+profiler sink imports jax.  With both sinks, a whole run's flight record
+can be exported to Perfetto with no profiler session.
 
-Span timestamps are wall-clock (``time.perf_counter``) because the point
+A tracer with neither sink returns one shared no-op context manager from
+``span()`` (no allocation), and ``begin``/``end`` return immediately —
+the scheduler instruments unconditionally against the process-wide
+default tracer, which starts disabled, so the hot path pays a handful of
+attribute checks per tick and nothing per VM.
+
+Ring timestamps are wall-clock (``time.perf_counter``) because the point
 is profiling real cost; pass the sim clock via span args when the sim
 instant matters (``tracer.span("x", t_sim=engine.clock.t)``).
 """
 from __future__ import annotations
 
+import gc
 import json
 import time
+import weakref
 from typing import Any, Callable, Dict, List, Optional
 
 
@@ -51,14 +62,17 @@ NULL_SPAN = _NullSpan()
 
 
 class _Span:
-    __slots__ = ("_tr", "name", "cat", "args", "_t0", "_depth")
+    __slots__ = ("_tr", "name", "cat", "args", "_t0", "_depth", "_ann",
+                 "_stepped")
 
     def __init__(self, tracer: "Tracer", name: str, cat: str,
-                 args: Optional[Dict[str, Any]]):
+                 args: Optional[Dict[str, Any]], stepped: bool = False):
         self._tr = tracer
         self.name = name
         self.cat = cat
         self.args = args
+        self._stepped = stepped
+        self._ann = None
 
     def set(self, **args) -> "_Span":
         """Attach/merge args after the span opened (e.g. batch sizes that
@@ -67,21 +81,30 @@ class _Span:
             self.args = args
         else:
             self.args.update(args)
+        if self._ann is not None:
+            self._ann.set_metadata(**args)
         return self
 
     def __enter__(self) -> "_Span":
         tr = self._tr
-        self._depth = len(tr._stack)
-        tr._stack.append(self.name)
-        self._t0 = tr._clock()
+        if tr.profiler:
+            self._ann = tr._annotation(self.name, self.args, self._stepped)
+            self._ann.__enter__()
+        if tr.enabled:
+            self._depth = len(tr._stack)
+            tr._stack.append(self.name)
+            self._t0 = tr._clock()
         return self
 
     def __exit__(self, *exc) -> bool:
         tr = self._tr
-        t1 = tr._clock()
-        tr._stack.pop()
-        tr._record(self.name, self.cat, self._t0, t1 - self._t0,
-                   self._depth, self.args)
+        if tr.enabled:
+            t1 = tr._clock()
+            tr._stack.pop()
+            tr._record(self.name, self.cat, self._t0, t1 - self._t0,
+                       self._depth, self.args)
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
         return False
 
 
@@ -89,10 +112,16 @@ class Tracer:
     """Ring-buffer flight recorder; see the module docstring."""
 
     def __init__(self, capacity: int = 65536, enabled: bool = True,
-                 clock: Callable[[], float] = time.perf_counter):
+                 clock: Callable[[], float] = time.perf_counter,
+                 profiler: bool = False):
         if capacity <= 0:
             raise ValueError("capacity must be positive")
-        self.enabled = enabled
+        self.enabled = enabled          # the ring records
+        self.profiler = profiler        # spans go to the JAX profiler too
+        if profiler:
+            from jax.profiler import StepTraceAnnotation, TraceAnnotation
+            self._ann_cls = (TraceAnnotation, StepTraceAnnotation)
+        self._gc_hook = None
         self.capacity = capacity
         self._clock = clock
         self._ring: List[Optional[tuple]] = [None] * capacity
@@ -105,31 +134,76 @@ class Tracer:
     def span(self, name: str, cat: str = "sched", **args):
         """Context manager recording one span on exit.  ``args`` land in
         the trace event's ``args`` payload."""
-        if not self.enabled:
+        if not (self.enabled or self.profiler):
             return NULL_SPAN
         return _Span(self, name, cat, args or None)
 
-    def begin(self, name: str, cat: str = "sched") -> None:
-        """Imperative open (for spans that cannot wrap a ``with`` block)."""
-        if not self.enabled:
-            return
-        self._stack.append(name)
-        self._begin_stack.append((name, cat, self._clock(),
-                                  len(self._stack) - 1))
+    def step(self, name: str, step_num: int, cat: str = "sched", **args):
+        """A span for one step of a loop, with arg ``step_num``: to the
+        profiler a ``StepTraceAnnotation``, which its step tools read."""
+        if not (self.enabled or self.profiler):
+            return NULL_SPAN
+        return _Span(self, name, cat, dict(args, step_num=step_num), True)
 
-    def end(self) -> None:
-        if not self.enabled or not self._begin_stack:
+    def begin(self, name: str, cat: str = "sched", **args) -> None:
+        """Imperative open (for spans that cannot wrap a ``with`` block)."""
+        if not (self.enabled or self.profiler):
             return
-        name, cat, t0, depth = self._begin_stack.pop()
-        self._stack.pop()
-        self._record(name, cat, t0, self._clock() - t0, depth, None)
+        ann = None
+        if self.profiler:
+            ann = self._annotation(name, args)
+            ann.__enter__()
+        if self.enabled:
+            self._stack.append(name)
+        self._begin_stack.append((name, cat, self._clock(),
+                                  len(self._stack) - 1, args or None, ann))
+
+    def end(self, **args) -> None:
+        """Close the innermost ``begin()``; ``args`` join its own."""
+        if not (self.enabled or self.profiler) or not self._begin_stack:
+            return
+        name, cat, t0, depth, a0, ann = self._begin_stack.pop()
+        if self.enabled:
+            self._stack.pop()
+            if args:
+                a0 = dict(a0 or (), **args)
+            self._record(name, cat, t0, self._clock() - t0, depth, a0)
+        if ann is not None:
+            if args:
+                ann.set_metadata(**args)
+            ann.__exit__(None, None, None)
 
     def instant(self, name: str, cat: str = "sched", **args) -> None:
-        """Zero-duration marker event."""
+        """Zero-duration marker event (ring only)."""
         if not self.enabled:
             return
         self._record(name, cat, self._clock(), 0.0, len(self._stack),
                      args or None)
+
+    def trace_gc(self) -> None:
+        """Record each Python garbage collection as a ``host.gc`` span
+        (args ``gen``, ``collected``) for as long as this tracer lives:
+        a collection stops the interpreter, and with it the host's part
+        of every step."""
+        if not (self.enabled or self.profiler) or self._gc_hook is not None:
+            return
+        ref = weakref.ref(self)
+
+        def hook(phase, info):
+            tr = ref()
+            if tr is None:
+                return
+            if phase == "start":
+                tr.begin("host.gc", cat="host", gen=info["generation"])
+            else:
+                tr.end(collected=info["collected"])
+        gc.callbacks.append(hook)
+        self._gc_hook = hook
+        weakref.finalize(self, gc.callbacks.remove, hook)
+
+    def _annotation(self, name: str, args: Optional[Dict[str, Any]],
+                    stepped: bool = False):
+        return self._ann_cls[stepped](name, **(args or {}))
 
     def _record(self, name: str, cat: str, t0: float, dur: float,
                 depth: int, args: Optional[Dict[str, Any]]) -> None:

@@ -17,7 +17,17 @@ serving tenant (``repro.agents.serving_agent``) drives:
 Time is injected (``now=``, defaulting to ``time.time`` for standalone
 use) so latency accounting works under the sim clock, and stats live in an
 ``obs.MetricDict`` with per-engine collectors (queue depth, active slots,
-tokens/s) plus token/request latency histograms on the injected registry.
+tokens/s) plus a token latency histogram on the injected registry.  A
+request's stamps (submit, admit to a slot, first token, done) split its
+time to first token into queue wait and prompt feed.
+
+Each step writes one span per phase through an ``obs.Tracer`` (``tracer=``;
+docs/OBSERVABILITY.md): ``engine.step`` around ``engine.shrink``,
+``engine.admit`` (with ``engine.reset_slot``), ``engine.decode``,
+``engine.sample``, ``engine.readback`` and ``engine.emit``, plus
+``host.gc`` for each garbage collection.  The real backend's default
+tracer writes them to the JAX profiler only, so a profiler session puts
+them on the device's timeline; outside one they cost a microsecond or two each.
 
 Two decode backends share every bit of the admission/slot/drain logic:
 
@@ -52,6 +62,7 @@ class Request:
     done: bool = False
     # latency stamps (engine ``now()`` timebase; submit may pre-stamp)
     t_submit: Optional[float] = None
+    t_admit: Optional[float] = None         # took a slot
     t_first_token: Optional[float] = None
     t_done: Optional[float] = None
 
@@ -83,7 +94,8 @@ class ServingEngine:
                  now: Optional[Callable[[], float]] = None,
                  registry: Optional[obs.MetricsRegistry] = None,
                  name: str = "engine",
-                 on_complete: Optional[Callable[[Request], None]] = None):
+                 on_complete: Optional[Callable[[Request], None]] = None,
+                 tracer: Optional[obs.Tracer] = None):
         self.cfg, self.pcfg, self.params = cfg, pcfg, params
         self.slots = batch_slots
         self.max_len = max_len
@@ -96,6 +108,15 @@ class ServingEngine:
         self._draining = False
         self._target_slots: Optional[int] = None    # pending deferred shrink
         self._synthetic = params is None
+        if tracer is None:
+            if self._synthetic:
+                tracer = obs.default_tracer()
+            else:
+                # the engine's own tracer: only it gets the gc hook; whoever
+                # builds a shared or passed-in tracer decides that for it
+                tracer = obs.Tracer(capacity=1, enabled=False, profiler=True)
+                tracer.trace_gc()
+        self.tracer = tracer
         if self._synthetic:
             self._pos = [0] * batch_slots
         else:
@@ -110,15 +131,14 @@ class ServingEngine:
         self._t0 = self._now()
         # defaultdict(float)-compatible stats, mirrored into registry gauges
         self.stats = obs.MetricDict(reg, prefix="wi_serving_", replica=name)
-        for k in ("requests", "tokens", "batches"):
+        for k in ("requests", "tokens", "batches", "admitted",
+                  "prompt_tokens"):
             self.stats[k] = 0
-        # latency distributions are shared series (no replica label) so one
-        # percentile read covers the whole fleet
+        # a shared series (no replica label) so one percentile read covers
+        # the whole fleet
         self._tok_lat = reg.histogram(
             "wi_serving_token_latency_s",
             "submit/last-emit to token emit (includes queue wait)")
-        self._req_lat = reg.histogram(
-            "wi_serving_request_latency_s", "submit to final token")
         reg.add_collector(f"serving.{name}", self._collect)
 
     def _collect(self):
@@ -254,25 +274,34 @@ class ServingEngine:
         self.stats["resizes"] += 1
 
     # -- loop ----------------------------------------------------------------
-    def _admit(self):
+    def _admit(self) -> int:
         """Fill free slots FIFO from the queue.  The prompt is fed
         token-by-token through the batched decode step (slot-level prefill
         interleaves with other slots' generation — continuous batching).
-        A pending shrink caps admissions at the target batch size."""
+        A pending shrink caps admissions at the target batch size.
+        Returns the number admitted."""
         cap = self._target_slots if self._target_slots is not None \
             else self.slots
         n_active = self.active_count()
+        n = 0
         for i in range(self.slots):
             if n_active >= cap or self._queue.empty():
                 break
             if self._active[i] is None:
                 req = self._queue.get()
+                req.t_admit = self._now()
                 req._pending = list(int(t) for t in req.prompt)
                 req._last = req._pending[-1]
                 self._active[i] = req
                 self._last_emit[i] = None
-                self._reset_slot(i)
+                with self.tracer.span("engine.reset_slot", cat="serve",
+                                      slot=i):
+                    self._reset_slot(i)
+                n += 1
                 n_active += 1
+        if n:
+            self.stats["admitted"] += n
+        return n
 
     def _reset_slot(self, i: int):
         if self._synthetic:
@@ -291,44 +320,85 @@ class ServingEngine:
             "index": self._cache["index"].at[i].set(0),
         }
 
-    def step_once(self) -> int:
-        """One batched decode step across all active slots (per-slot cache
-        positions diverge; cache['index'] is a per-slot vector)."""
-        self._maybe_apply_shrink()
-        self._admit()
-        live = [i for i, r in enumerate(self._active) if r is not None]
-        if not live:
-            return 0
-        now = self._now()
-        toks = np.zeros((self.slots, 1), np.int32)
-        for i in live:
-            r = self._active[i]
-            toks[i, 0] = r._pending[0] if r._pending else r._last
-        if self._synthetic:
-            # deterministic pure-python "greedy decode": the next token is
-            # a fixed function of the fed token, independent of co-batched
-            # slots — same determinism contract as the jax path
-            nxt = (5 * toks[:, 0] + 7) % _SYNTH_VOCAB
+    def _decode_step(self, live: List[int]):
+        """The batched decode step over the live slots: each slot's next
+        token and its cache position after the step, on the host, and the
+        number of slots that fed a prompt token."""
+        tr = self.tracer
+        with tr.span("engine.decode", cat="serve"):
+            toks = np.zeros((self.slots, 1), np.int32)
+            prompt = 0
             for i in live:
-                self._pos[i] += 1
-            idx = np.asarray(self._pos)
-        else:
+                r = self._active[i]
+                if r._pending:
+                    toks[i, 0] = r._pending[0]
+                    prompt += 1
+                else:
+                    toks[i, 0] = r._last
+            if self._synthetic:
+                # deterministic pure-python "greedy decode": the next token
+                # is a fixed function of the fed token, independent of
+                # co-batched slots — same determinism contract as the jax
+                # path
+                for i in live:
+                    self._pos[i] += 1
+                return (5 * toks[:, 0] + 7) % _SYNTH_VOCAB, \
+                    np.asarray(self._pos), prompt
             import jax
             import jax.numpy as jnp
             logits, self._cache = self._decode(self.params, self._cache,
                                                jnp.asarray(toks))
+        with tr.span("engine.sample", cat="serve"):
             self._key, sub = jax.random.split(self._key)
-            nxt = np.asarray(sample(logits[:, 0], 0.0, sub))
-            idx = np.asarray(self._cache["index"])
+            nxt = sample(logits[:, 0], 0.0, sub)
+        # the host waits here for the device to finish the step
+        with tr.span("engine.readback", cat="serve"):
+            return np.asarray(nxt), np.asarray(self._cache["index"]), prompt
+
+    def step_once(self) -> int:
+        """One batched decode step across all active slots (per-slot cache
+        positions diverge; cache['index'] is a per-slot vector)."""
+        tr = self.tracer
+        with tr.step("engine.step", int(self.stats["batches"]),
+                     cat="serve") as sp:
+            if self._target_slots is not None:
+                with tr.span("engine.shrink", cat="serve",
+                             to=self._target_slots):
+                    self._maybe_apply_shrink()
+            with tr.span("engine.admit", cat="serve") as asp:
+                admitted = self._admit()
+                asp.set(n=admitted)
+            live = [i for i, r in enumerate(self._active) if r is not None]
+            if not live:
+                sp.set(live=0, admitted=admitted, prompt=0)
+                return 0
+            nxt, idx, prompt = self._decode_step(live)
+            sp.set(live=len(live), admitted=admitted, prompt=prompt)
+            # one clock read after the tokens reached the host stamps every
+            # token and completion of this step
+            now = self._now()
+            with tr.span("engine.emit", cat="serve") as esp:
+                emitted, done = self._emit(live, nxt, idx, now)
+                esp.set(emitted=emitted, done=done)
+            self.stats["tokens"] += len(live)
+            self.stats["prompt_tokens"] += prompt
+            self.stats["batches"] += 1
+        return len(live)
+
+    def _emit(self, live: List[int], nxt: np.ndarray, idx: np.ndarray,
+              now: float):
+        """Hand each slot its token; finish the requests that are done.
+        Returns (tokens emitted, requests finished)."""
+        emitted = done = 0
         for i in live:
             r = self._active[i]
-            emit = False
             if r._pending:
                 r._pending.pop(0)
                 emit = not r._pending   # prompt consumed: first real token
             else:
                 emit = True
             if emit:
+                emitted += 1
                 r.out_tokens.append(int(nxt[i]))
                 r._last = int(nxt[i])
                 # token latency: gap since the previous emit, or the full
@@ -339,20 +409,17 @@ class ServingEngine:
                     prev = r.t_submit if r.t_submit is not None else now
                 self._tok_lat.observe(max(0.0, now - prev))
                 self._last_emit[i] = now
-            self.stats["tokens"] += 1
             if len(r.out_tokens) >= r.max_new or idx[i] >= self.max_len - 1:
+                done += 1
                 r.done = True
                 r.t_done = now
                 self._active[i] = None
                 self._last_emit[i] = None
                 self.stats["completed"] += 1
                 self.stats["tokens_out"] += len(r.out_tokens)
-                if r.t_submit is not None:
-                    self._req_lat.observe(max(0.0, now - r.t_submit))
                 if self._on_complete is not None:
                     self._on_complete(r)
-        self.stats["batches"] += 1
-        return len(live)
+        return emitted, done
 
     def run_until_drained(self, max_steps: int = 10_000):
         steps = 0

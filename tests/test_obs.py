@@ -168,6 +168,28 @@ def test_disabled_tracer_returns_the_shared_null_span():
     assert tr.recorded == 0 and tr.dropped == 0
 
 
+def test_tracer_sinks_ring_and_profiler_apart_and_together():
+    only = obs.Tracer(capacity=4, enabled=False, profiler=True)
+    with only.span("x", a=1) as sp:
+        assert sp is not obs.NULL_SPAN
+        sp.set(b=2)
+    with only.step("s", 3):
+        pass
+    assert only.recorded == 0
+    both = obs.Tracer(capacity=8, profiler=True)
+    with both.step("engine.step", 7, live=2) as sp:
+        sp.set(admitted=1)
+        both.begin("host.gc", cat="host", gen=0)
+        both.end(collected=5)
+    both.instant("mark", k=1)
+    gc_ev, step_ev, mark = both.events()
+    assert gc_ev[:2] == ("host.gc", "host") and gc_ev[4] == 1
+    assert gc_ev[5] == {"gen": 0, "collected": 5}
+    assert step_ev[0] == "engine.step"
+    assert step_ev[5] == {"live": 2, "step_num": 7, "admitted": 1}
+    assert mark[0] == "mark" and mark[3] == 0.0
+
+
 # ---------------------------------------------------------------------------
 # lifecycle observer
 # ---------------------------------------------------------------------------
