@@ -15,9 +15,8 @@ import jax.numpy as jnp
 from repro.configs.base import ModelConfig, ParallelConfig
 from repro.models.layers import basic
 from repro.models.layers.attention import (attn_axes, attn_params,
-                                           decode_attention_local,
-                                           dense_attention, finalize_decode,
-                                           qkv)
+                                           decode_attention_stacked,
+                                           dense_attention, qkv)
 from repro.models.layers.flash import flash_attention
 from repro.models.layers.moe import moe, moe_axes, moe_params
 from repro.models.layers.rglru import (rglru_axes, rglru_block, rglru_params,
@@ -80,8 +79,14 @@ def sublayer_axes(cfg: ModelConfig, kind: str):
 # ---------------------------------------------------------------------------
 
 def apply_sublayer(cfg: ModelConfig, pcfg: ParallelConfig, kind: str, p, x,
-                   positions, enc_out=None, cache=None, decode_index=None):
-    """Returns (x_new, aux_loss, new_cache_entry)."""
+                   positions, enc_out=None, cache=None, decode_index=None,
+                   layer=None):
+    """Returns (x_new, aux_loss, new_cache_entry).
+
+    In decode (``decode_index`` given) ``cache`` is the group's stacked
+    entry, layers first, and ``layer`` this sublayer's index in it; the
+    returned entry is that stacked cache with this layer's new state
+    written in."""
     acfg = _acfg(cfg, kind)
     h = basic.rmsnorm(p["norm_in"], x, cfg.rms_eps)
     aux = jnp.zeros((), jnp.float32)
@@ -107,15 +112,18 @@ def apply_sublayer(cfg: ModelConfig, pcfg: ParallelConfig, kind: str, p, x,
         else:                       # single-token decode against the cache
             q, k, v = qkv(p["core"], h, cfg.n_heads, cfg.n_kv_heads,
                           cfg.head_dim, positions, cfg.rope_theta)
-            idx = jnp.broadcast_to(jnp.asarray(decode_index), (h.shape[0],))
-            upd = jax.vmap(lambda c, u, s: jax.lax.dynamic_update_slice(
-                c, u, (s, 0, 0)))
-            new_cache = dict(cache)
-            new_cache["k"] = upd(cache["k"], k.astype(cache["k"].dtype), idx)
-            new_cache["v"] = upd(cache["v"], v.astype(cache["v"].dtype), idx)
-            num, den, m = decode_attention_local(
-                q, new_cache["k"], new_cache["v"], idx + 1, acfg)
-            o = finalize_decode(num, den, m).astype(h.dtype)
+            B = h.shape[0]
+            idx = jnp.broadcast_to(jnp.asarray(decode_index), (B,))
+            # one row per slot at [layer, slot, index]; an index past the
+            # end overwrites the last row, as dynamic_update_slice clamps
+            row = jnp.clip(idx, 0, cache["k"].shape[2] - 1)
+            slot = jnp.arange(B)
+            new_cache = {n: cache[n].at[layer, slot, row].set(
+                t[:, 0].astype(cache[n].dtype))
+                for n, t in (("k", k), ("v", v))}
+            o = decode_attention_stacked(
+                q, new_cache["k"], new_cache["v"], layer, idx + 1,
+                acfg).astype(h.dtype)
             o = o.reshape(h.shape[0], 1, cfg.n_heads * cfg.head_dim)
             h = o @ p["core"]["wo"]
 
@@ -130,9 +138,8 @@ def apply_sublayer(cfg: ModelConfig, pcfg: ParallelConfig, kind: str, p, x,
             if cache is not None:
                 new_cache = {"k": k.astype(cache["k"].dtype),
                              "v": v.astype(cache["v"].dtype)}
-        else:
-            k, v = cache["k"], cache["v"]
-            new_cache = cache
+        else:                       # read-only: passed through unwritten
+            k, v = cache["k"][layer], cache["v"][layer]
         B, Sd, _ = h.shape
         q = (h @ p["core"]["wq"]).reshape(B, Sd, cfg.n_heads, cfg.head_dim)
         from repro.configs.base import AttnConfig
@@ -150,21 +157,21 @@ def apply_sublayer(cfg: ModelConfig, pcfg: ParallelConfig, kind: str, p, x,
     elif kind == "moe":
         h, aux = moe(p["core"], h, cfg.moe, cap_shard=pcfg.moe_cap_shard)
 
-    elif kind == "ssd":
-        st = None if cache is None or decode_index is None else cache["state"]
-        cv = None if cache is None or decode_index is None else cache["conv"]
-        h, (new_st, new_cv) = ssd_block(p["core"], h, cfg.ssd, cfg.d_model,
-                                        state=st, conv_state=cv,
-                                        rms_eps=cfg.rms_eps)
-        if cache is not None:
-            new_cache = {"state": new_st, "conv": new_cv}
-
-    elif kind == "rglru":
-        st = None if cache is None or decode_index is None else cache["state"]
-        cv = None if cache is None or decode_index is None else cache["conv"]
-        h, (new_st, new_cv) = rglru_block(p["core"], h, cfg.rglru,
-                                          state=st, conv_state=cv)
-        if cache is not None:
+    elif kind in ("ssd", "rglru"):
+        decoding = cache is not None and decode_index is not None
+        st = cache["state"][layer] if decoding else None
+        cv = cache["conv"][layer] if decoding else None
+        if kind == "ssd":
+            h, (new_st, new_cv) = ssd_block(p["core"], h, cfg.ssd, cfg.d_model,
+                                            state=st, conv_state=cv,
+                                            rms_eps=cfg.rms_eps)
+        else:
+            h, (new_st, new_cv) = rglru_block(p["core"], h, cfg.rglru,
+                                              state=st, conv_state=cv)
+        if decoding:                # the layer's whole (small) state
+            new_cache = {n: cache[n].at[layer].set(t.astype(cache[n].dtype))
+                         for n, t in (("state", new_st), ("conv", new_cv))}
+        elif cache is not None:
             new_cache = {"state": new_st, "conv": new_cv}
     else:
         raise ValueError(kind)

@@ -20,7 +20,10 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.base import AttnConfig
+from repro.kernels.decode_attention.decode_attention import (
+    stacked_decode_attention)
 from repro.models.layers.basic import _leaf, apply_rope
+from repro.models.sharding import get_mesh
 
 A = jax.ShapeDtypeStruct
 NEG_INF = -0.7 * float(np.finfo(np.float32).max)
@@ -121,6 +124,32 @@ def decode_attention_local(q, k_cache, v_cache, valid_len, cfg: AttnConfig,
     num = jnp.einsum("bkrs,bskd->bkrd", p.astype(v_cache.dtype), v_cache)
     num = num.astype(jnp.float32)
     return (num.reshape(B, 1, H, hd), den.reshape(B, 1, H), m.reshape(B, 1, H))
+
+
+def decode_attention_stacked(q, k_stack, v_stack, layer, valid_len,
+                             cfg: AttnConfig):
+    """Decode attention over layer ``layer`` of stacked caches
+    k/v_stack [L, B, S, K, hd] -> [B, 1, H, hd] f32.
+
+    Compiled for a TPU, a Pallas kernel reads the layer's blocks straight
+    from the stack.  Elsewhere, and on a mesh (the kernel would be handed
+    the whole sharded stack), XLA slices the layer out: on a TPU that is a
+    copy of the layer's whole K and V."""
+    def sliced(q, k_stack, v_stack, layer, valid_len):
+        return finalize_decode(*decode_attention_local(
+            q, k_stack[layer], v_stack[layer], valid_len, cfg))
+
+    def in_place(q, k_stack, v_stack, layer, valid_len):
+        scale = (cfg.query_scale if cfg.query_scale is not None
+                 else 1.0 / np.sqrt(q.shape[-1]))
+        return stacked_decode_attention(
+            q, k_stack, v_stack, layer, valid_len, scale=scale,
+            window=cfg.window, softcap=cfg.logit_softcap)
+
+    args = (q, k_stack, v_stack, layer, valid_len)
+    if get_mesh() is not None:
+        return sliced(*args)
+    return jax.lax.platform_dependent(*args, tpu=in_place, default=sliced)
 
 
 def combine_decode_partials(num, den, m, axis_name):

@@ -165,18 +165,29 @@ def _fsdp_gather_axes(cfg, pattern):
 
 def _run_groups(cfg, pcfg, groups_p, patterns, x, positions, enc_out=None,
                 caches=None, decode_index=None, remat=True):
-    """Scan every group.  Returns (x, aux_sum, new_caches)."""
+    """Scan every group.  Returns (x, aux_sum, new_caches).
+
+    Prefill scans each group's cache as ``xs`` and stacks what its layers
+    write as ``ys``: it writes whole prompts.  Decode writes one row a
+    layer, so there the group's stacked cache is part of the scan carry and
+    each layer writes its rows into it in place.  As ``xs``/``ys`` every
+    layer's cache would be sliced out, written back into a fresh stacked
+    buffer, and that buffer copied once more onto the donated input."""
     aux_total = jnp.zeros((), jnp.float32)
     new_caches = [] if caches is not None else None
+    in_place = caches is not None and decode_index is not None
 
-    for gi, (pattern, _) in enumerate(patterns):
+    for gi, (pattern, r) in enumerate(patterns):
         unit_p = groups_p[gi]
         cache_g = caches[gi] if caches is not None else None
         gather_axes = _fsdp_gather_axes(cfg, pattern) if pcfg.fsdp else None
 
         def body(carry, xs, _pattern=pattern, _gather=gather_axes):
-            xx, aux = carry
-            up, uc = xs
+            if in_place:
+                (xx, aux, uc), (up, li) = carry, xs
+            else:
+                (xx, aux), (up, uc) = carry, xs
+                li = None
             if _gather is not None:
                 up = jax.tree.map(lambda w, ax: constrain(w, ax), up, _gather)
                 if pcfg.gather_barrier:
@@ -193,12 +204,15 @@ def _run_groups(cfg, pcfg, groups_p, patterns, x, positions, enc_out=None,
                     c_in = c_in if c_in else None  # {} placeholder -> None
                     xx, a, c_out = blocks.apply_sublayer(
                         cfg, pcfg, kind, up[key], xx, positions,
-                        enc_out=enc_out, cache=c_in, decode_index=decode_index)
+                        enc_out=enc_out, cache=c_in, decode_index=decode_index,
+                        layer=li)
                     if pcfg.seq_shard_acts and decode_index is None:
                         xx = constrain(xx, ("batch", "seq", None))
                     aux = aux + a
                     if ncache is not None:
                         ncache[key] = c_out if c_out is not None else {}
+            if in_place:
+                return (xx, aux, ncache), None
             return (xx, aux), ncache
 
         if remat and decode_index is None and pcfg.remat != "none":
@@ -207,9 +221,13 @@ def _run_groups(cfg, pcfg, groups_p, patterns, x, positions, enc_out=None,
             fn = jax.remat(body, policy=policy)
         else:
             fn = body
-        xs = (unit_p, cache_g if cache_g is not None
-              else jax.tree.map(lambda v: v, {k: {} for k in unit_p}))
-        (x, aux_total), ys = jax.lax.scan(fn, (x, aux_total), xs)
+        if in_place:
+            (x, aux_total, ys), _ = jax.lax.scan(
+                fn, (x, aux_total, cache_g), (unit_p, jnp.arange(r)))
+        else:
+            xs = (unit_p, cache_g if cache_g is not None
+                  else jax.tree.map(lambda v: v, {k: {} for k in unit_p}))
+            (x, aux_total), ys = jax.lax.scan(fn, (x, aux_total), xs)
         if new_caches is not None:
             new_caches.append(ys)
     return x, aux_total, new_caches

@@ -142,9 +142,23 @@ def test_kernel_compiles_at_real_width(name, smoke, one_chip):
     assert "tpu_custom_call" in compiled.as_text()
 
 
+def _moves(hlo, shapes):
+    """Instructions of an HLO text, fused ones included, that copy or
+    (dynamic-)slice-and-write an array of one of ``shapes``."""
+    out = []
+    for m in re.finditer(r"%(\S+) = \w+\[([\d,]*)\]\S* "
+                         r"(copy|dynamic-slice|dynamic-update-slice)\(",
+                         hlo):
+        if tuple(int(d) for d in m.group(2).split(",") if d) in shapes:
+            out.append(m.group(1))
+    return out
+
+
 def test_serving_programs_fit_one_chip(smoke, one_chip):
     """The 8-layer minitron-8b decode step at 16 slots x 2048 positions,
-    with its cache donated, and the jitted weight draw."""
+    with its cache donated, and the jitted weight draw.  The decode
+    writes its new rows into the donated cache in place: it neither copies
+    the stacked cache nor slices a layer's K or V out of it."""
     cfg = mconfig_replace(ARCHS["minitron-8b"], n_layers=smoke.SERVE_LAYERS)
     params = _on(M.abstract_params(cfg), one_chip)
     cache = _on(M.init_cache(cfg, 16, 2048, abstract=True), one_chip)
@@ -152,7 +166,12 @@ def test_serving_programs_fit_one_chip(smoke, one_chip):
     decode = decode_fn(cfg, SERVE_PCFG).lower(params, cache, toks).compile()
     cache_bytes = sum(a.size * a.dtype.itemsize
                       for a in jax.tree.leaves(cache))
-    assert decode.memory_analysis().alias_size_in_bytes >= cache_bytes
+    memory = decode.memory_analysis()
+    assert memory.alias_size_in_bytes >= cache_bytes
+    assert memory.temp_size_in_bytes < cache_bytes / 8
+    stack = cache["groups"][0]["0.attn"]["k"].shape   # [8, 16, 2048, 8, 128]
+    assert _moves(decode.as_text(),
+                  {stack, (1,) + stack[1:], stack[1:]}) == []
     assert _device_bytes(decode) < HBM_BYTES
 
     key = jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=one_chip)

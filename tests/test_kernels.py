@@ -11,6 +11,7 @@ pytest.importorskip("hypothesis")   # property tests need it; skip cleanly if ab
 from hypothesis import given, settings, strategies as st
 
 from repro.configs.base import AttnConfig
+from repro.kernels.decode_attention import decode_attention as da
 from repro.kernels.flash_attention import ops as fa_ops
 from repro.kernels.flash_attention import ref as fa_ref
 from repro.kernels.rglru import ops as lru_ops
@@ -171,3 +172,35 @@ def test_rglru_kernel_channel_blocks():
     np.testing.assert_allclose(np.asarray(out),
                                np.asarray(lru_ref.reference(x, log_a)),
                                atol=1e-4, rtol=1e-3)
+
+
+# ---------------------------------------------------------------------------
+# decode attention over one layer of a stacked cache
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("n_blocks", [1, 4])
+@pytest.mark.parametrize("window,softcap", [(None, None), (16, 30.0)])
+def test_stacked_decode_kernel_matches_sliced_layer(n_blocks, window,
+                                                    softcap, monkeypatch):
+    """Each layer of the stack, read in place, against XLA's decode
+    attention over that layer sliced out; slots at different lengths, one
+    past the cache's end (attends every position)."""
+    from repro.models.layers.attention import (decode_attention_local,
+                                               finalize_decode)
+    L, B, S, K, rep, hd = 3, 4, 64, 2, 3, 128
+    monkeypatch.setattr(da, "BLOCK_BYTES", S // n_blocks * K * hd * 2)
+    cfg = AttnConfig(window=window, logit_softcap=softcap)
+    ks = jax.random.split(jax.random.PRNGKey(n_blocks), 3)
+    q = rand(ks[0], (B, 1, K * rep, hd), jnp.bfloat16)
+    kc = rand(ks[1], (L, B, S, K, hd), jnp.bfloat16)
+    vc = rand(ks[2], (L, B, S, K, hd), jnp.bfloat16)
+    valid = jnp.array([1, 23, S, S + 1])
+    for layer in range(L):
+        ref = finalize_decode(*decode_attention_local(
+            q, kc[layer], vc[layer], valid, cfg))
+        out = da.stacked_decode_attention(
+            q, kc, vc, jnp.int32(layer), valid, scale=1 / np.sqrt(hd),
+            window=window, softcap=softcap, interpret=True)
+        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                                   atol=TOL[jnp.bfloat16],
+                                   rtol=TOL[jnp.bfloat16])

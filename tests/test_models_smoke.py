@@ -100,6 +100,72 @@ def test_prefill_decode_matches_forward(name):
             rtol=0.15, atol=0.15)
 
 
+def _prefilled(cfg, params, key, n_pos, max_len):
+    """A batch-of-one cache holding ``n_pos`` prefilled positions."""
+    nv = cfg.n_vision_tokens if cfg.family == "vlm" else 0
+    if cfg.family == "encdec":   # one encoder length for every slot
+        batch = make_batch(cfg, key, batch=1, seq=4 * max_len, train=False)
+        enc_len = batch["frames"].shape[1]
+    else:
+        batch = make_batch(cfg, key, batch=1, seq=n_pos, train=False)
+        enc_len = 0
+    batch["tokens"] = batch["tokens"][:, :n_pos - nv]
+    cache = M.init_cache(cfg, 1, max_len, enc_len=enc_len)
+    _, cache = M.prefill(cfg, PCFG, params, batch, cache)
+    assert int(cache["index"][0]) == n_pos
+    return cache
+
+
+@pytest.mark.parametrize("name", sorted(ARCHS))
+def test_decode_at_mixed_positions_matches_each_alone(name):
+    """Continuous batching: slots at different positions (the last one the
+    cache holds among them) decode together as each would alone, and the
+    step writes nothing but the slots' new rows."""
+    cfg = smoke_config(name)
+    params = M.init_params(cfg, KEY)
+    max_len = 33
+    positions = [12, max_len - 1, 16]   # prefill lengths the SSD chunks fit
+    keys = jax.random.split(jax.random.PRNGKey(1), len(positions) + 1)
+    solo = [_prefilled(cfg, params, k, n, max_len)
+            for k, n in zip(keys, positions)]
+    # cache leaves are [layers, slots, ...], the index [slots]
+    cache = jax.tree.map(
+        lambda *c: jnp.concatenate(c, axis=1 if c[0].ndim > 1 else 0), *solo)
+    toks = jax.random.randint(keys[-1], (len(positions), 1), 0,
+                              cfg.vocab_size)
+    step = jax.jit(lambda c, t: M.decode_step(cfg, PCFG, params, c, t))
+    logits, new = step(cache, toks)
+    np.testing.assert_array_equal(np.asarray(new["index"]),
+                                  np.asarray(positions) + 1)
+
+    for s, c1 in enumerate(solo):
+        l1, n1 = step(c1, toks[s:s + 1])
+        np.testing.assert_allclose(np.asarray(logits[s]), np.asarray(l1[0]),
+                                   rtol=1e-4, atol=1e-4)
+        for got, want in zip(jax.tree.leaves(new["groups"]),
+                             jax.tree.leaves(n1["groups"])):
+            np.testing.assert_allclose(
+                np.asarray(got[:, s], np.float32),
+                np.asarray(want[:, 0], np.float32), rtol=1e-2, atol=1e-2)
+
+    written = np.zeros((len(positions), max_len), bool)
+    written[np.arange(len(positions)), positions] = True
+    for gi, group in enumerate(new["groups"]):
+        for key, entry in group.items():
+            kind = key.split(".", 1)[1]
+            old = cache["groups"][gi][key]
+            if kind in ("attn", "attn_local"):
+                for leaf in ("k", "v"):
+                    a, b = np.asarray(entry[leaf]), np.asarray(old[leaf])
+                    np.testing.assert_array_equal(a[:, ~written],
+                                                  b[:, ~written])
+                    assert not np.array_equal(a[:, written], b[:, written])
+            elif kind == "cross_attn":
+                for leaf in ("k", "v"):
+                    np.testing.assert_array_equal(np.asarray(entry[leaf]),
+                                                  np.asarray(old[leaf]))
+
+
 def test_count_params_matches_tree():
     for name in ARCHS:
         cfg = smoke_config(name)
